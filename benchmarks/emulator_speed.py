@@ -47,6 +47,7 @@ import jax
 import jax.numpy as jnp
 
 from benchmarks import common as C
+from repro.compile_cache import enable_compile_cache
 from repro.core import engine
 from repro.core.types import FabricConfig, PlatformModel, WorkloadConfig
 from repro.workloads import MultiTenant
@@ -283,6 +284,7 @@ def main() -> None:
     ap.add_argument("--reps", type=int, default=None,
                     help="timed repetitions per variant (post-warmup)")
     args = ap.parse_args()
+    enable_compile_cache()
     C.jit_warmup()
     header, rows = bench(quick=args.quick, reps=args.reps)
     C.write_csv("emulator_speed", header, rows)

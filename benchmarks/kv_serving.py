@@ -38,6 +38,7 @@ import jax
 
 from benchmarks import common as C
 from repro import configs
+from repro.compile_cache import enable_compile_cache
 from repro.core.types import CacheConfig, EngineConfig, FabricConfig, SSDConfig
 from repro.serving import kv_tier
 
@@ -241,6 +242,7 @@ def main() -> None:
     ap.add_argument("--quick", action="store_true",
                     help="reduced sweep/steps for CI smoke")
     args = ap.parse_args()
+    enable_compile_cache()
     C.jit_warmup()
     (h27, r27), (h28, r28) = bench(quick=args.quick)
     C.write_csv("fig27_kv_serving_iops", h27, r27)

@@ -22,12 +22,14 @@ def main() -> None:
     args = ap.parse_args()
 
     from benchmarks import common as C
+    from repro.compile_cache import enable_compile_cache
     from benchmarks.emulator_speed import bench_figure, sanitize_pass
     from benchmarks.figures import ALL
 
     # One warmup invocation before anything is timed: the first jit call
     # of the process pays backend init + dispatch warm-up on top of its
     # own compile, which would otherwise land in the first figure's time.
+    enable_compile_cache()
     C.jit_warmup()
 
     if args.sanitize:

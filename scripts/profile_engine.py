@@ -38,6 +38,7 @@ import jax  # noqa: E402
 
 from benchmarks import common as C  # noqa: E402
 from benchmarks.emulator_speed import _configs  # noqa: E402
+from repro.compile_cache import enable_compile_cache  # noqa: E402
 from repro.core import engine, frontend, qp, timing  # noqa: E402
 from repro.core import datapath, flash  # noqa: E402
 from repro.core.device import DevicePipeline  # noqa: E402
@@ -190,6 +191,7 @@ def main() -> int:
         spec["cfg"] = spec["cfg"].replace(lock_order=args.lock_order)
     cfg, ssd, wl = spec["cfg"], spec["ssd"], spec["wl"]
     plat = PlatformModel()
+    enable_compile_cache()
     C.jit_warmup()
 
     # -- optional sanitized certification pass -----------------------------
@@ -222,13 +224,10 @@ def main() -> int:
             )
         st = jax.block_until_ready(runner(st))  # warmup/compile round
         Path(args.outdir).mkdir(parents=True, exist_ok=True)
-        try:
-            with jax.profiler.trace(args.outdir):
-                st = jax.block_until_ready(runner(st))
-            print(f"trace: 1 x {args.rounds}-round invocation -> "
-                  f"{args.outdir}")
-        except Exception as e:  # noqa: BLE001 — profiling is best-effort
-            print(f"trace: SKIPPED ({type(e).__name__}: {e})")
+        with jax.profiler.trace(args.outdir):
+            st = jax.block_until_ready(runner(st))
+        print(f"trace: 1 x {args.rounds}-round invocation -> "
+              f"{args.outdir}")
 
     # -- per-stage cost table ----------------------------------------------
     print(f"\nper-stage cost, config={args.config} "

@@ -2,9 +2,10 @@
 
 Functional emulation: the flash address space is an HBM-resident array of
 blocks; a read gathers ``flash[lba] -> bufs[buf_id]``, a write scatters the
-reverse. On TPU the gather runs as the ``block_gather`` Pallas kernel (the
-DSA analogue: a batch of copy descriptors per grid step, double-buffered
-DMA); on CPU / in tests the jnp reference path is used.
+reverse. With ``EngineConfig.use_pallas`` the gather runs as the
+``block_gather`` Pallas kernel (the DSA analogue: a batch of row DMAs per
+grid step; compiled, it needs 128-word flash rows); otherwise the jnp
+gather is used.
 
 Virtual-time model: the *baseline* backend charges each request the
 map/unmap software overhead plus a small sequential CPU copy (paper Fig. 4),

@@ -614,11 +614,10 @@ def make_sharded_array_runner(
 
     Where ``make_array_runner`` vmaps the whole array onto one
     accelerator, this shards the stacked ``EngineState``'s leading
-    device axis over a 1-D mesh via ``shard_map`` (the version-portable
-    shim in ``distributed/sharding.py``) and vmaps each shard locally —
-    so an M-drive array spreads over however many real devices the
-    process holds, one XLA program per shard. M must be divisible by
-    the mesh size. With a 1-device mesh this is semantically identical
+    device axis over a 1-D mesh via ``jax.shard_map`` and vmaps each
+    shard locally — so an M-drive array spreads over however many real
+    devices the process holds, one XLA program per shard. M must be
+    divisible by the mesh size. With a 1-device mesh this is semantically identical
     to ``make_array_runner`` (asserted bit-exactly in
     ``tests/test_fabric.py``).
 
@@ -626,8 +625,6 @@ def make_sharded_array_runner(
     """
     import numpy as np
     from jax.sharding import Mesh, PartitionSpec as P
-
-    from repro.distributed.sharding import shard_map
 
     wl = as_workload(wl)
     if mesh is None:
@@ -638,8 +635,9 @@ def make_sharded_array_runner(
             lambda s: run(s, cfg, ssd, wl, plat, rounds)
         )(states)
 
-    sharded = jax.jit(shard_map(
-        _shard, mesh, in_specs=P(axis_name), out_specs=P(axis_name)
+    sharded = jax.jit(jax.shard_map(
+        _shard, mesh=mesh, in_specs=P(axis_name), out_specs=P(axis_name),
+        check_vma=False,
     ))
     mesh_size = int(np.prod(mesh.devices.shape))
 

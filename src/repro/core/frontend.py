@@ -134,9 +134,11 @@ def submit_grouped(
     Rows must be pre-sorted by submit time.
 
     ``fused`` collapses the seven per-field ring scatters into one
-    stacked (Q, F, 7) pass: the six i32 fields ride as raw bits via
-    ``bitcast_convert_type`` (scatters move bits, never arithmetic, so
-    the round-trip is exact and the rings land bit-identical).
+    stacked (Q, F, 7) i32 pass: the f32 submit time rides as raw bits
+    via ``bitcast_convert_type`` (scatters move bits, never arithmetic,
+    so the round-trip is exact and the rings land bit-identical). The
+    stack is i32, not f32: an i32 below 2^23 read as f32 bits is a
+    subnormal, which the TPU flushes to zero.
     """
     q, f = submit_time.shape
     if tenant is None:
@@ -149,38 +151,27 @@ def submit_grouped(
 
     if fused:
         bits = jax.lax.bitcast_convert_type
-
-        def f32(x):
-            return bits(x, jnp.float32)
-
+        fields = (opcode, lba, nblocks, buf_id, req_id, tenant)
         page = jnp.stack(
-            [
-                submit_time, f32(opcode), f32(lba), f32(nblocks),
-                f32(buf_id), f32(req_id), f32(tenant),
-            ],
-            axis=-1,
+            [bits(submit_time, jnp.int32), *fields], axis=-1
         )
         stacked = jnp.stack(
             [
-                rings.submit_time, f32(rings.opcode), f32(rings.lba),
-                f32(rings.nblocks), f32(rings.buf_id), f32(rings.req_id),
-                f32(rings.tenant),
+                bits(rings.submit_time, jnp.int32), rings.opcode,
+                rings.lba, rings.nblocks, rings.buf_id, rings.req_id,
+                rings.tenant,
             ],
             axis=-1,
         ).at[rows, pos].set(page, mode="drop")
-
-        def i32(x):
-            return bits(x, jnp.int32)
-
         return dataclasses.replace(
             rings,
-            submit_time=stacked[..., 0],
-            opcode=i32(stacked[..., 1]),
-            lba=i32(stacked[..., 2]),
-            nblocks=i32(stacked[..., 3]),
-            buf_id=i32(stacked[..., 4]),
-            req_id=i32(stacked[..., 5]),
-            tenant=i32(stacked[..., 6]),
+            submit_time=bits(stacked[..., 0], jnp.float32),
+            opcode=stacked[..., 1],
+            lba=stacked[..., 2],
+            nblocks=stacked[..., 3],
+            buf_id=stacked[..., 4],
+            req_id=stacked[..., 5],
+            tenant=stacked[..., 6],
             tail=tail,
         )
 

@@ -102,9 +102,11 @@ def _scatter_entries(
     ``counts`` lets the caller hand in per-CQ valid counts it already
     knows (the compacted epoch's block counts) instead of paying a
     segment_sum. ``fused`` replaces the three ring scatters with one
-    stacked (N, 3) scatter — the i32 ``req_id`` channel rides as raw
-    bits via ``bitcast_convert_type`` (scatters move bits, never
-    arithmetic, so the round-trip is exact).
+    stacked (N, 3) i32 scatter — the f32 time channels ride as raw bits
+    via ``bitcast_convert_type`` (scatters move bits, never arithmetic,
+    so the round-trip is exact). The stack is i32, not f32: an i32
+    below 2^23 read as f32 bits is a subnormal, which the TPU flushes
+    to zero.
     """
     q, d = cq.num_cqs, cq.depth
     row = jnp.clip(key, 0, q - 1)
@@ -118,18 +120,19 @@ def _scatter_entries(
     # is reaped within it, so the head tracks the tail.
     if fused:
         bits = jax.lax.bitcast_convert_type
-        page = jnp.stack(
-            [done, visible, bits(req_id, jnp.float32)], axis=-1
-        )
+
+        def i32(x):
+            return bits(x, jnp.int32)
+
+        page = jnp.stack([i32(done), i32(visible), req_id], axis=-1)
         rings = jnp.stack(
-            [cq.done_time, cq.visible_time, bits(cq.req_id, jnp.float32)],
-            axis=-1,
+            [i32(cq.done_time), i32(cq.visible_time), cq.req_id], axis=-1
         ).at[row, pos].set(page, mode="drop")
         return dataclasses.replace(
             cq,
-            done_time=rings[..., 0],
-            visible_time=rings[..., 1],
-            req_id=bits(rings[..., 2], jnp.int32),
+            done_time=bits(rings[..., 0], jnp.float32),
+            visible_time=bits(rings[..., 1], jnp.float32),
+            req_id=rings[..., 2],
             tail=cq.tail + counts,
             head=cq.head + counts,
         )

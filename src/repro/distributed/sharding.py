@@ -12,23 +12,6 @@ from typing import Any, Sequence
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-if hasattr(jax, "shard_map"):
-    def shard_map(f, mesh, in_specs, out_specs, check_vma=False):
-        """Version-portable shard_map (jax >= 0.6 top-level API)."""
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=check_vma,
-        )
-else:
-    from jax.experimental.shard_map import shard_map as _exp_shard_map
-
-    def shard_map(f, mesh, in_specs, out_specs, check_vma=False):
-        """Version-portable shard_map (jax < 0.6 experimental API; its
-        ``check_rep`` flag plays the role of ``check_vma``)."""
-        return _exp_shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_rep=check_vma,
-        )
 
 # Default production rules. None ⇒ replicated. An axis only binds when the
 # dimension is divisible by the mesh extent (spec_for checks shapes), so

@@ -9,6 +9,8 @@ in dispatch order, carrying a (K,) busy-cursor vector in the output
 ref: row i on die c observes ``b = max(cur[c], ready_i) + cost_i`` and
 advances ``cur[c] = b``.
 
+All operands are scalars in SMEM (the fold is scalar work by nature).
+
 The fold evaluates the queueing recurrence literally, while the lax
 reference re-associates it (a segmented max-plus scan) — the two agree
 bit-exactly only when timestamps are integer-valued (the same contract
@@ -23,26 +25,31 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _die_contention_kernel(
     ready_ref, cost_ref, chip_ref, event_ref, cur_in, busy_ref, cur_out
 ):
-    cur_out[...] = cur_in[...]
-    busy_ref[...] = jnp.zeros_like(busy_ref)
-    n = ready_ref.shape[1]
+    def copy(c, carry):
+        cur_out[c] = cur_in[c]
+        return carry
+
+    jax.lax.fori_loop(0, cur_in.shape[0], copy, 0)
 
     def body(i, carry):
-        @pl.when(event_ref[0, i] != 0)
+        busy_ref[i] = jnp.float32(0)
+
+        @pl.when(event_ref[i] != 0)
         def _ev():
-            c = chip_ref[0, i]
-            b = jnp.maximum(cur_out[0, c], ready_ref[0, i]) + cost_ref[0, i]
-            busy_ref[0, i] = b
-            cur_out[0, c] = b
+            c = chip_ref[i]
+            b = jnp.maximum(cur_out[c], ready_ref[i]) + cost_ref[i]
+            busy_ref[i] = b
+            cur_out[c] = b
 
         return carry
 
-    jax.lax.fori_loop(0, n, body, 0)
+    jax.lax.fori_loop(0, ready_ref.shape[0], body, 0)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -53,34 +60,21 @@ def die_contention(
     event: jax.Array,      # (N,) bool rows that occupy their die
     chip_busy: jax.Array,  # (K,) f32 epoch-start die cursors
     *,
-    interpret: bool = True,
+    interpret: bool = False,
 ):
     """Returns (busy, new_cursors): per-row die-service completion (0 for
     non-event rows — the flash stage never reads those) and the advanced
     (K,) cursors."""
     n = ready.shape[0]
     k = chip_busy.shape[0]
-    busy, cur = pl.pallas_call(
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    return pl.pallas_call(
         _die_contention_kernel,
-        grid=(1,),
-        in_specs=[
-            pl.BlockSpec((1, n), lambda i: (0, 0)),
-            pl.BlockSpec((1, n), lambda i: (0, 0)),
-            pl.BlockSpec((1, n), lambda i: (0, 0)),
-            pl.BlockSpec((1, n), lambda i: (0, 0)),
-            pl.BlockSpec((1, k), lambda i: (0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, n), lambda i: (0, 0)),
-            pl.BlockSpec((1, k), lambda i: (0, 0)),
-        ],
+        in_specs=[smem] * 5,
+        out_specs=[smem, smem],
         out_shape=[
-            jax.ShapeDtypeStruct((1, n), jnp.float32),
-            jax.ShapeDtypeStruct((1, k), jnp.float32),
+            jax.ShapeDtypeStruct((n,), jnp.float32),
+            jax.ShapeDtypeStruct((k,), jnp.float32),
         ],
         interpret=interpret,
-    )(
-        ready[None, :], cost[None, :], chip[None, :],
-        event.astype(jnp.int32)[None, :], chip_busy[None, :],
-    )
-    return busy[0], cur[0]
+    )(ready, cost, chip, event.astype(jnp.int32), chip_busy)

@@ -1,9 +1,11 @@
 """Public jit'd wrappers for the Pallas kernels.
 
-On a TPU backend the kernels compile natively; everywhere else (this CPU
-container, unit tests) they execute in ``interpret=True`` mode, which runs
-the kernel body in Python against the same BlockSpec pipeline — the
-correctness contract tested against ref.py holds in both modes.
+On a TPU backend the kernels compile natively. On the CPU backend (the
+test suite) they run in ``interpret=True`` mode, which executes the
+kernel body against the same BlockSpec pipeline — the correctness
+contract tested against ref.py holds in both modes. Any other backend
+is an error: a kernel never falls back to the interpreter or to a
+reference path in silence.
 """
 from __future__ import annotations
 
@@ -18,15 +20,21 @@ from repro.kernels import seg_scan as _ss
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    backend = jax.default_backend()
+    if backend not in ("tpu", "cpu"):
+        raise RuntimeError(
+            f"Pallas kernels compile for TPU and are interpreted on CPU; "
+            f"the {backend!r} backend has neither path"
+        )
+    return backend == "cpu"
 
 
 def block_gather(flash, idx):
     return _bg.block_gather(flash, idx, interpret=_interpret())
 
 
-def seg_scan(values, heads, *, chunk: int = 256):
-    return _ss.seg_scan(values, heads, chunk=chunk, interpret=_interpret())
+def seg_scan(values, heads):
+    return _ss.seg_scan(values, heads, interpret=_interpret())
 
 
 def fused_reap(done_time, visible_time, req_id_ring, tail, key, done,

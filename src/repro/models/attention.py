@@ -10,7 +10,7 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.distributed.sharding import constrain, shard_map
+from repro.distributed.sharding import constrain
 from repro.models import layers
 from repro.models.config import ATTN_LOCAL, ModelConfig
 
@@ -201,7 +201,7 @@ def _sharded_flash(q, k, v, cfg: ModelConfig, window, scale):
 
     qspec = P(dp, "model", None, None)
     kvspec = P(dp, None, None, None)
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(qspec, kvspec, kvspec),
         out_specs=qspec,
@@ -286,7 +286,7 @@ def _megatron_attention(
         return y
 
     x_spec = P(dp, "model", None)
-    y = shard_map(
+    y = jax.shard_map(
         body, mesh=mesh,
         in_specs=(wspec, x_spec, pos_spec, mpos_spec),
         out_specs=x_spec,

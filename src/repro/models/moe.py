@@ -200,7 +200,7 @@ def _ep_moe(params: dict, x: jax.Array, cfg: ModelConfig, mesh, rules):
         )
         return out, aux
 
-    return shd.shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(wspec, x_spec),
         out_specs=(x_spec, P()),
@@ -254,7 +254,7 @@ def moe_apply(
         aux = cfg.router_aux_coef * e * jnp.sum(f_e * p_e)
         return out.reshape(bl, sl, d), aux
 
-    out, aux = shd.shard_map(
+    out, aux = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(wspec, x_spec),

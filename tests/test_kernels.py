@@ -9,7 +9,7 @@ import numpy as np
 import pytest
 
 from repro.kernels import ref
-from repro.kernels.block_gather import block_gather, block_gather_tiled
+from repro.kernels.block_gather import block_gather
 from repro.kernels.decode_attention import decode_attention
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.seg_scan import seg_scan
@@ -21,6 +21,7 @@ from repro.kernels.seg_scan import seg_scan
 
 @pytest.mark.parametrize("nblocks,width,n", [
     (64, 128, 32), (256, 128, 256), (128, 256, 64), (32, 512, 8),
+    (64, 16, 1000), (4096, 128, 1500),
 ])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_block_gather(nblocks, width, n, dtype):
@@ -33,11 +34,20 @@ def test_block_gather(nblocks, width, n, dtype):
     )
 
 
-@pytest.mark.parametrize("tile", [4, 8])
-def test_block_gather_tiled(tile):
+def test_block_gather_refuses_narrow_rows_when_compiled():
+    """A compiled row DMA needs whole 128-lane rows: a 16-word image is
+    refused with an error naming the kernel, not copied per call."""
+    flash = jnp.zeros((64, 16), jnp.float32)
+    with pytest.raises(ValueError, match="block_gather"):
+        block_gather(flash, jnp.zeros((8,), jnp.int32), interpret=False)
+
+
+@pytest.mark.parametrize("batch", [8, 64])
+def test_block_gather_batches(batch):
+    """Descriptor batches per grid step: ragged tails pad and slice off."""
     flash = jax.random.normal(jax.random.PRNGKey(2), (64, 128))
-    idx = jax.random.randint(jax.random.PRNGKey(3), (32,), 0, 64)
-    out = block_gather_tiled(flash, idx, tile=tile, interpret=True)
+    idx = jax.random.randint(jax.random.PRNGKey(3), (100,), 0, 64)
+    out = block_gather(flash, idx, batch=batch, interpret=True)
     np.testing.assert_array_equal(
         np.asarray(out), np.asarray(ref.block_gather_ref(flash, idx))
     )
@@ -47,14 +57,16 @@ def test_block_gather_tiled(tile):
 # seg_scan
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("n,chunk", [(16, 8), (256, 64), (100, 32), (1, 8)])
-def test_seg_scan(n, chunk):
+@pytest.mark.parametrize("n,block_rows", [
+    (16, 8), (3000, 8), (100, 256), (1, 8), (20000, 64),
+])
+def test_seg_scan(n, block_rows):
     rng = np.random.default_rng(n)
     vals = rng.uniform(-100, 100, n).astype(np.float32)
     heads = rng.random(n) < 0.2
     heads[0] = True
-    out = seg_scan(jnp.asarray(vals), jnp.asarray(heads), chunk=chunk,
-                   interpret=True)
+    out = seg_scan(jnp.asarray(vals), jnp.asarray(heads),
+                   block_rows=block_rows, interpret=True)
     expect = ref.seg_scan_ref(jnp.asarray(vals), jnp.asarray(heads))
     np.testing.assert_allclose(np.asarray(out), np.asarray(expect), rtol=1e-6)
 
