@@ -273,9 +273,8 @@ for order in ("program", "ready_time"):
 #     free-page and fabric cursor invariants). The checks only observe —
 #     the sanitized run's final state is bitwise identical to the
 #     default run's (tests/test_sanitize.py) — but the program is
-#     slower, so it's off by default; benchmarks/run.py --sanitize and
-#     scripts/profile_engine.py --sanitize run it as a certification
-#     pass before timing anything. A violated invariant raises
+#     slower, so it's off by default; benchmarks/run.py --sanitize runs
+#     it as a certification pass before timing anything. A violated invariant raises
 #     checkify.JaxRuntimeError with the failed check's message.
 san_runner = engine.make_runner(fast_cfg, ssd, wl, PlatformModel(),
                                 rounds=8, sanitize=True)

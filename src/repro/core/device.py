@@ -404,16 +404,19 @@ class DevicePipeline:
         compact = cfg.use_compaction
         blocky = compact and ring_layout
         pallas = cfg.resolve_pallas_segscan(ssd, plat)
-        unit_rank = segops.presorted_plan(unit).rank if use_plan else None
-        if blocky:
-            cq_rank = segops.block_masked_rank(valid, cfg.fetch_width)
-            cq_counts = segops.block_counts(valid, cfg.fetch_width)
-        else:
-            cq_rank = (
-                segops.masked_presorted_rank(batch.sq_id, valid)
-                if use_plan else None
+        with jax.named_scope("stage.lock"):
+            unit_rank = (
+                segops.presorted_plan(unit).rank if use_plan else None
             )
-            cq_counts = None
+            if blocky:
+                cq_rank = segops.block_masked_rank(valid, cfg.fetch_width)
+                cq_counts = segops.block_counts(valid, cfg.fetch_width)
+            else:
+                cq_rank = (
+                    segops.masked_presorted_rank(batch.sq_id, valid)
+                    if use_plan else None
+                )
+                cq_counts = None
 
         # -- stage 1.5: fabric TX hop (remote drives only). Fetched SQEs
         # (plus write payloads) cross the wire before the target-side
@@ -423,17 +426,20 @@ class DevicePipeline:
         fab_tx, fab_rx = state.fabric.tx_busy, state.fabric.rx_busy
         sw_tx, sw_rx = state.fabric.switch_tx, state.fabric.switch_rx
         if fab.remote:
-            tx_bytes = fabric_mod.tx_wire_bytes(batch, plat.sqe_bytes, ssd)
-            if fab.switched:
-                sw_tx, fetch_done = fabric_mod.switch_hop(
-                    sw_tx, fetch_done, tx_bytes, valid, fab, tenant,
+            with jax.named_scope("stage.fabric_tx"):
+                tx_bytes = fabric_mod.tx_wire_bytes(
+                    batch, plat.sqe_bytes, ssd
+                )
+                if fab.switched:
+                    sw_tx, fetch_done = fabric_mod.switch_hop(
+                        sw_tx, fetch_done, tx_bytes, valid, fab, tenant,
+                        fused_sort=use_plan, use_pallas=pallas,
+                    )
+                fab_tx, fetch_done = fabric_mod.fabric_hop(
+                    fab_tx, fetch_done, tx_bytes,
+                    valid, fab, fab.tx_bytes_per_us, tenant,
                     fused_sort=use_plan, use_pallas=pallas,
                 )
-            fab_tx, fetch_done = fabric_mod.fabric_hop(
-                fab_tx, fetch_done, tx_bytes,
-                valid, fab, fab.tx_bytes_per_us, tenant,
-                fused_sort=use_plan, use_pallas=pallas,
-            )
 
         # -- stage 2a: global timing-model lock over the admission epoch.
         # The post-TX ``fetch_done`` *defines* the epoch's ready times (a
@@ -443,16 +449,17 @@ class DevicePipeline:
         # maxes, exact under any association) and segmented forms on the
         # direct path. ``cfg.lock_order`` decides acquisition order; see
         # ``acquire_lock``.
-        epoch = Epoch.from_batch(
-            batch, fetch_done, unit, "ring" if ring_layout else "direct"
-        )
-        n_valid_u = epoch.unit_counts(u)
-        lock_time, lock_done, unit_order = acquire_lock(
-            state.lock_time, epoch, u, cfg, plat
-        )
-        disp_time = jnp.maximum(state.disp_time, lock_done)
-        epoch = epoch.admit(lock_done)
-        arrival = epoch.arrival
+        with jax.named_scope("stage.lock"):
+            epoch = Epoch.from_batch(
+                batch, fetch_done, unit, "ring" if ring_layout else "direct"
+            )
+            n_valid_u = epoch.unit_counts(u)
+            lock_time, lock_done, unit_order = acquire_lock(
+                state.lock_time, epoch, u, cfg, plat
+            )
+            disp_time = jnp.maximum(state.disp_time, lock_done)
+            epoch = epoch.admit(lock_done)
+            arrival = epoch.arrival
 
         # -- stage 2b: target completion times. Under the ready-time lock
         # the shared timing state is updated in lock-acquisition order:
@@ -460,82 +467,91 @@ class DevicePipeline:
         # unit program order holds), via a pure gather/scatter row
         # permutation — the float expression tree inside timing.update is
         # the verbatim reference one either way.
-        tbatch = dataclasses.replace(batch, arrival=arrival)
-        dispatch_order = (
-            admission_row_order(unit_order, epoch, u)
-            if unit_order is not None else None
-        )
-        if cfg.timing_scope == "local":
-            tstate, target = timing.local_scope_update(
-                state.tstate, arrival, valid, ssd, u,
-                use_compaction=compact,
+        with jax.named_scope("stage.timing"):
+            tbatch = dataclasses.replace(batch, arrival=arrival)
+            dispatch_order = (
+                admission_row_order(unit_order, epoch, u)
+                if unit_order is not None else None
             )
-        else:
-            tstate, target = timing.update(
-                state.tstate, tbatch, ssd, cfg.mode, use_compaction=compact,
-                dispatch_order=dispatch_order,
-            )
-
-        # -- stage 3: backend data transfer.
-        if cfg.batched_datapath:
-            # DSA engine also carried the fetch transfer (engine sharing /
-            # interference, paper Fig. 9b): bump cursors by fetch bytes.
-            # count * sqe_bytes == the segment_sum of the constant bit-
-            # for-bit: every partial sum of equal integer-valued f32
-            # terms below 2^24 is exact under any association.
-            if blocky:
-                fetch_bytes_u = n_valid_u.astype(jnp.float32) * jnp.float32(
-                    plat.sqe_bytes
+            if cfg.timing_scope == "local":
+                tstate, target = timing.local_scope_update(
+                    state.tstate, arrival, valid, ssd, u,
+                    use_compaction=compact,
                 )
             else:
-                fetch_bytes_u = jax.ops.segment_sum(
-                    jnp.where(valid, jnp.float32(plat.sqe_bytes), 0.0),
-                    unit, num_segments=u,
+                tstate, target = timing.update(
+                    state.tstate, tbatch, ssd, cfg.mode,
+                    use_compaction=compact, dispatch_order=dispatch_order,
                 )
-            dsa_time0 = state.dsa_time + fetch_bytes_u / plat.dsa_bytes_per_us
-            dsa_time, ready = datapath.dsa_worker_times(
-                dsa_time0, arrival, batch, cfg, plat, ssd, unit=unit
-            )
-            work_time, map_time = state.work_time, state.map_time
-        else:
-            work_time, map_time, ready = datapath.baseline_worker_times(
-                state.work_time, state.map_time, arrival, batch, cfg, plat,
-                ssd, unit=unit, unit_rank=unit_rank,
-                use_counting_sort=compact,
-            )
-            dsa_time = state.dsa_time
+
+        # -- stage 3: backend data transfer.
+        with jax.named_scope("stage.datapath"):
+            if cfg.batched_datapath:
+                # DSA engine also carried the fetch transfer (engine
+                # sharing / interference, paper Fig. 9b): bump cursors by
+                # fetch bytes. count * sqe_bytes == the segment_sum of the
+                # constant bit-for-bit: every partial sum of equal
+                # integer-valued f32 terms below 2^24 is exact under any
+                # association.
+                if blocky:
+                    fetch_bytes_u = n_valid_u.astype(
+                        jnp.float32
+                    ) * jnp.float32(plat.sqe_bytes)
+                else:
+                    fetch_bytes_u = jax.ops.segment_sum(
+                        jnp.where(valid, jnp.float32(plat.sqe_bytes), 0.0),
+                        unit, num_segments=u,
+                    )
+                dsa_time0 = (
+                    state.dsa_time + fetch_bytes_u / plat.dsa_bytes_per_us
+                )
+                dsa_time, ready = datapath.dsa_worker_times(
+                    dsa_time0, arrival, batch, cfg, plat, ssd, unit=unit
+                )
+                work_time, map_time = state.work_time, state.map_time
+            else:
+                work_time, map_time, ready = datapath.baseline_worker_times(
+                    state.work_time, state.map_time, arrival, batch, cfg,
+                    plat, ssd, unit=unit, unit_rank=unit_rank,
+                    use_counting_sort=compact,
+                )
+                dsa_time = state.dsa_time
 
         # -- stage 4: flash-level backend (writes, GC, mapping misses).
-        if ssd.flash_backend:
-            fstate, flash_done = flash_stage(
-                state.flash, batch, arrival, target, ssd, use_pallas=pallas,
-                use_counting_sort=compact,
-                use_pallas_flash=cfg.use_pallas_flash,
-            )
-        else:
-            fstate, flash_done = state.flash, jnp.where(valid, arrival, 0.0)
+        with jax.named_scope("stage.flash"):
+            if ssd.flash_backend:
+                fstate, flash_done = flash_stage(
+                    state.flash, batch, arrival, target, ssd,
+                    use_pallas=pallas, use_counting_sort=compact,
+                    use_pallas_flash=cfg.use_pallas_flash,
+                )
+            else:
+                fstate = state.flash
+                flash_done = jnp.where(valid, arrival, 0.0)
 
-        done = jnp.where(
-            valid, jnp.maximum(jnp.maximum(target, ready), flash_done), 0.0
-        )
+            done = jnp.where(
+                valid, jnp.maximum(jnp.maximum(target, ready), flash_done),
+                0.0,
+            )
 
         # -- stage 4.5: fabric RX hop. Completions (plus read payloads)
         # cross back to the initiator — over this drive's link first,
         # then the shared switch port all M return streams converge on
         # (incast) — before they reach its CQ.
         if fab.remote:
-            rx_bytes = fabric_mod.rx_wire_bytes(batch, fab, ssd)
-            fab_rx, wire_done = fabric_mod.fabric_hop(
-                fab_rx, done, rx_bytes,
-                valid, fab, fab.rx_bytes_per_us, tenant,
-                fused_sort=use_plan, use_pallas=pallas,
-            )
-            if fab.switched:
-                sw_rx, wire_done = fabric_mod.switch_hop(
-                    sw_rx, wire_done, rx_bytes, valid, fab, tenant,
+            with jax.named_scope("stage.fabric_rx"):
+                rx_bytes = fabric_mod.rx_wire_bytes(batch, fab, ssd)
+                fab_rx, wire_done = fabric_mod.fabric_hop(
+                    fab_rx, done, rx_bytes,
+                    valid, fab, fab.rx_bytes_per_us, tenant,
                     fused_sort=use_plan, use_pallas=pallas,
                 )
-            wire_done = jnp.where(valid, wire_done, 0.0)
+                if fab.switched:
+                    sw_rx, wire_done = fabric_mod.switch_hop(
+                        sw_rx, wire_done, rx_bytes, valid, fab, tenant,
+                        fused_sort=use_plan, use_pallas=pallas,
+                    )
+                wire_done = jnp.where(valid, wire_done, 0.0)
         else:
             wire_done = done
 
@@ -553,21 +569,24 @@ class DevicePipeline:
         if cq is None:
             reaped = wire_done
         else:
-            cq, reaped = qp.post_and_reap(
-                cq, batch.sq_id, wire_done, batch.req_id, valid, cfg.qp,
-                posted_rank=cq_rank, fused_sort=use_plan, use_pallas=pallas,
-                posted_counts=cq_counts, fused_scatter=compact,
-                use_pallas_reap=cfg.use_pallas_reap,
-            )
+            with jax.named_scope("stage.cq"):
+                cq, reaped = qp.post_and_reap(
+                    cq, batch.sq_id, wire_done, batch.req_id, valid, cfg.qp,
+                    posted_rank=cq_rank, fused_sort=use_plan,
+                    use_pallas=pallas, posted_counts=cq_counts,
+                    fused_scatter=compact,
+                    use_pallas_reap=cfg.use_pallas_reap,
+                )
         res = PipelineResult(
             arrival=arrival, target=target, ready=ready,
             flash_done=flash_done, done=done, reaped=reaped,
         )
         if cfg.sanitize:
-            _sanitize_checks(
-                cfg, state, new_state, batch, res,
-                dispatch_order, cq_counts,
-            )
+            with jax.named_scope("stage.sanitize"):
+                _sanitize_checks(
+                    cfg, state, new_state, batch, res,
+                    dispatch_order, cq_counts,
+                )
         return new_state, cq, res
 
     def _submit_direct(

@@ -310,13 +310,18 @@ def engine_round(
     pipe = DevicePipeline(cfg, ssd, plat)
     q, f = cfg.num_sqs, cfg.fetch_width
 
+    # Every stage runs under a ``stage.<name>`` named scope: XLA keeps the
+    # scope in each instruction's ``op_name``, so a device trace of the
+    # fused round can be read by stage. The scopes change no computation.
+
     # -- 1. frontend fetch ---------------------------------------------------
-    rings, disp_time, batch, fetch_done = frontend.fetch(
-        state.rings, state.clock, state.device.disp_time, cfg, plat
-    )
+    with jax.named_scope("stage.fetch"):
+        rings, disp_time, batch, fetch_done = frontend.fetch(
+            state.rings, state.clock, state.device.disp_time, cfg, plat
+        )
+        unit = frontend.fetch_row_units(cfg)
     submit_t = batch.arrival                       # provisional = submit time
     n = batch.valid.shape[0]
-    unit = frontend.fetch_row_units(cfg)
 
     # -- 2-5. the unified device pipeline (timing + data path + QP) ----------
     dev = dataclasses.replace(state.device, disp_time=disp_time)
@@ -332,44 +337,51 @@ def engine_round(
     # -- completion metrics: the consumer observes ``reaped`` (post-CQ) ------
     valid = batch.valid
     done = res.reaped
-    e2e = jnp.where(valid, done - submit_t, 0.0)
-    tgt_lat = jnp.where(valid, res.target - res.arrival, 0.0)
-    proc = jnp.where(valid, res.ready - res.arrival, 0.0)
-    nvalid = jnp.sum(valid.astype(jnp.float32))
-    lat_hist = jax.ops.segment_sum(
-        valid.astype(jnp.float32), latency_bucket(e2e),
-        num_segments=HIST_BUCKETS,
-    )
-    # Per-tenant (QoS class) completion accounting: T is static (the
-    # metrics' bucket count, fixed at init).
-    n_ten = state.metrics.tenant_completed.shape[0]
-    t_bucket = jnp.clip(batch.tenants, 0, n_ten - 1)
-    tenant_completed = jax.ops.segment_sum(
-        valid.astype(jnp.float32), t_bucket, num_segments=n_ten
-    )
-    tenant_sum_e2e = jax.ops.segment_sum(e2e, t_bucket, num_segments=n_ten)
-    tenant_lat_hist = jnp.zeros((n_ten, HIST_BUCKETS), jnp.float32).at[
-        t_bucket, latency_bucket(e2e)
-    ].add(valid.astype(jnp.float32), mode="drop")
+    with jax.named_scope("stage.account"):
+        e2e = jnp.where(valid, done - submit_t, 0.0)
+        tgt_lat = jnp.where(valid, res.target - res.arrival, 0.0)
+        proc = jnp.where(valid, res.ready - res.arrival, 0.0)
+        nvalid = jnp.sum(valid.astype(jnp.float32))
+        lat_hist = jax.ops.segment_sum(
+            valid.astype(jnp.float32), latency_bucket(e2e),
+            num_segments=HIST_BUCKETS,
+        )
+        # Per-tenant (QoS class) completion accounting: T is static (the
+        # metrics' bucket count, fixed at init).
+        n_ten = state.metrics.tenant_completed.shape[0]
+        t_bucket = jnp.clip(batch.tenants, 0, n_ten - 1)
+        tenant_completed = jax.ops.segment_sum(
+            valid.astype(jnp.float32), t_bucket, num_segments=n_ten
+        )
+        tenant_sum_e2e = jax.ops.segment_sum(
+            e2e, t_bucket, num_segments=n_ten
+        )
+        tenant_lat_hist = jnp.zeros((n_ten, HIST_BUCKETS), jnp.float32).at[
+            t_bucket, latency_bucket(e2e)
+        ].add(valid.astype(jnp.float32), mode="drop")
 
     # -- functional data movement --------------------------------------------
     flash, bufs = state.flash, state.bufs
     if cfg.emulate_data:
-        bufs = datapath.apply_reads(flash, bufs, batch, cfg.use_pallas)
-        flash = datapath.apply_writes(flash, bufs, batch)
+        with jax.named_scope("stage.data_read"):
+            bufs = datapath.apply_reads(flash, bufs, batch, cfg.use_pallas)
+        with jax.named_scope("stage.data_write"):
+            flash = datapath.apply_writes(flash, bufs, batch)
 
     # -- workload-driven resubmission (stage-0 cache filters first) ----------
     # Rows are SQ-major (q, f); a row's tenant is its SQ's static class.
-    tenant_rows = jnp.repeat(
-        wl.tenant_of_sq(jnp.arange(q, dtype=jnp.int32), cfg, state.salt), f
-    )
-    new_req = state.req_counter + jnp.arange(n, dtype=jnp.int32)
-    new_lba = wl.address(new_req, ssd, state.salt)
-    new_op = wl.opcode(new_req, state.salt, tenant=tenant_rows)
-    anchor = jnp.repeat(state.last_submit, f)
-    resub_t, resub_valid = wl.next_submit(
-        new_req, done, valid, anchor, cfg, ssd, state.salt
-    )
+    with jax.named_scope("stage.resubmit"):
+        tenant_rows = jnp.repeat(
+            wl.tenant_of_sq(jnp.arange(q, dtype=jnp.int32), cfg, state.salt),
+            f,
+        )
+        new_req = state.req_counter + jnp.arange(n, dtype=jnp.int32)
+        new_lba = wl.address(new_req, ssd, state.salt)
+        new_op = wl.opcode(new_req, state.salt, tenant=tenant_rows)
+        anchor = jnp.repeat(state.last_submit, f)
+        resub_t, resub_valid = wl.next_submit(
+            new_req, done, valid, anchor, cfg, ssd, state.salt
+        )
 
     cstate = state.cache
     ccfg = cfg.cache
@@ -380,116 +392,119 @@ def engine_round(
     hit_bucket = jnp.zeros((HIST_BUCKETS,), jnp.float32)
     ids_per_round = n
     if ccfg.enabled:
-        # Fills: this round's completed device reads are now GPU-resident.
-        cstate = cache_mod.insert(
-            cstate, batch.lba, valid & (batch.opcode == OP_READ), ccfg
+        with jax.named_scope("stage.cache"):
+            # Fills: this round's completed device reads are now GPU-resident.
+            cstate = cache_mod.insert(
+                cstate, batch.lba, valid & (batch.opcode == OP_READ), ccfg
+            )
+            # Hit chase: a proposed read that hits completes at GPU-local
+            # latency without ever posting an SQE, and the slot immediately
+            # proposes its next request — up to ``chase`` hits per slot per
+            # round; the survivor (first miss or chase-truncated request)
+            # is what actually enters the rings.
+            for k in range(ccfg.chase):
+                hit, done_h = cache_mod.serve(
+                    cstate, new_lba,
+                    resub_valid & (new_op == OP_READ), resub_t, ccfg,
+                )
+                nh = jnp.sum(hit.astype(jnp.float32))
+                hits_count = hits_count + nh
+                hit_e2e = hit_e2e + nh * jnp.float32(ccfg.hit_us)
+                hit_last = jnp.maximum(
+                    hit_last, jnp.max(jnp.where(hit, done_h, 0.0))
+                )
+                hit_first = jnp.minimum(
+                    hit_first, jnp.min(jnp.where(hit, resub_t, FAR))
+                )
+                hit_bucket = hit_bucket.at[
+                    latency_bucket(jnp.float32(ccfg.hit_us))
+                ].add(nh, mode="drop")
+                ids = (
+                    state.req_counter
+                    + n * (k + 1)
+                    + jnp.arange(n, dtype=jnp.int32)
+                )
+                s_lba = wl.address(ids, ssd, state.salt)
+                s_op = wl.opcode(ids, state.salt, tenant=tenant_rows)
+                s_t, s_valid = wl.next_submit(
+                    ids, done_h, hit, anchor, cfg, ssd, state.salt
+                )
+                new_lba = jnp.where(hit, s_lba, new_lba)
+                new_op = jnp.where(hit, s_op, new_op)
+                new_req = jnp.where(hit, ids, new_req)
+                resub_t = jnp.where(hit, s_t, resub_t)
+                resub_valid = jnp.where(hit, s_valid, resub_valid)
+            ids_per_round = n * (ccfg.chase + 1)
+
+    with jax.named_scope("stage.account"):
+        m = state.metrics
+        metrics = Metrics(
+            completed=m.completed + nvalid + hits_count,
+            fetched=m.fetched + nvalid,
+            sum_e2e=m.sum_e2e + jnp.sum(e2e) + hit_e2e,
+            sum_target=m.sum_target + jnp.sum(tgt_lat),
+            sum_proc=m.sum_proc + jnp.sum(proc),
+            last_completion=jnp.maximum(
+                jnp.maximum(
+                    m.last_completion, jnp.max(jnp.where(valid, done, 0.0))
+                ),
+                hit_last,
+            ),
+            first_submit=jnp.minimum(
+                jnp.minimum(
+                    m.first_submit, jnp.min(jnp.where(valid, submit_t, FAR))
+                ),
+                hit_first,
+            ),
+            lat_hist=m.lat_hist + lat_hist + hit_bucket,
+            cache_hits=m.cache_hits + hits_count,
+            tenant_completed=m.tenant_completed + tenant_completed,
+            tenant_sum_e2e=m.tenant_sum_e2e + tenant_sum_e2e,
+            tenant_lat_hist=m.tenant_lat_hist + tenant_lat_hist,
         )
-        # Hit chase: a proposed read that hits completes at GPU-local
-        # latency without ever posting an SQE, and the slot immediately
-        # proposes its next request — up to ``chase`` hits per slot per
-        # round; the survivor (first miss or chase-truncated request)
-        # is what actually enters the rings.
-        for k in range(ccfg.chase):
-            hit, done_h = cache_mod.serve(
-                cstate, new_lba,
-                resub_valid & (new_op == OP_READ), resub_t, ccfg,
-            )
-            nh = jnp.sum(hit.astype(jnp.float32))
-            hits_count = hits_count + nh
-            hit_e2e = hit_e2e + nh * jnp.float32(ccfg.hit_us)
-            hit_last = jnp.maximum(
-                hit_last, jnp.max(jnp.where(hit, done_h, 0.0))
-            )
-            hit_first = jnp.minimum(
-                hit_first, jnp.min(jnp.where(hit, resub_t, FAR))
-            )
-            hit_bucket = hit_bucket.at[
-                latency_bucket(jnp.float32(ccfg.hit_us))
-            ].add(nh, mode="drop")
-            ids = (
-                state.req_counter
-                + n * (k + 1)
-                + jnp.arange(n, dtype=jnp.int32)
-            )
-            s_lba = wl.address(ids, ssd, state.salt)
-            s_op = wl.opcode(ids, state.salt, tenant=tenant_rows)
-            s_t, s_valid = wl.next_submit(
-                ids, done_h, hit, anchor, cfg, ssd, state.salt
-            )
-            new_lba = jnp.where(hit, s_lba, new_lba)
-            new_op = jnp.where(hit, s_op, new_op)
-            new_req = jnp.where(hit, ids, new_req)
-            resub_t = jnp.where(hit, s_t, resub_t)
-            resub_valid = jnp.where(hit, s_valid, resub_valid)
-        ids_per_round = n * (ccfg.chase + 1)
 
-    m = state.metrics
-    metrics = Metrics(
-        completed=m.completed + nvalid + hits_count,
-        fetched=m.fetched + nvalid,
-        sum_e2e=m.sum_e2e + jnp.sum(e2e) + hit_e2e,
-        sum_target=m.sum_target + jnp.sum(tgt_lat),
-        sum_proc=m.sum_proc + jnp.sum(proc),
-        last_completion=jnp.maximum(
-            jnp.maximum(
-                m.last_completion, jnp.max(jnp.where(valid, done, 0.0))
+    with jax.named_scope("stage.resubmit"):
+        resub_t = jnp.where(resub_valid, resub_t, FAR)
+        last_submit = jnp.maximum(
+            state.last_submit,
+            jnp.max(
+                jnp.where(resub_valid, resub_t, 0.0).reshape(q, f), axis=1
             ),
-            hit_last,
-        ),
-        first_submit=jnp.minimum(
-            jnp.minimum(
-                m.first_submit, jnp.min(jnp.where(valid, submit_t, FAR))
-            ),
-            hit_first,
-        ),
-        lat_hist=m.lat_hist + lat_hist + hit_bucket,
-        cache_hits=m.cache_hits + hits_count,
-        tenant_completed=m.tenant_completed + tenant_completed,
-        tenant_sum_e2e=m.tenant_sum_e2e + tenant_sum_e2e,
-        tenant_lat_hist=m.tenant_lat_hist + tenant_lat_hist,
-    )
+        )
+        # Rows are SQ-major (q, f); sort each SQ's resubmissions by time.
+        rt = resub_t.reshape(q, f)
+        order = segops.stable_argsort(rt, axis=1)
+        rows = jnp.arange(q, dtype=jnp.int32)[:, None]
 
-    resub_t = jnp.where(resub_valid, resub_t, FAR)
-    last_submit = jnp.maximum(
-        state.last_submit,
-        jnp.max(
-            jnp.where(resub_valid, resub_t, 0.0).reshape(q, f), axis=1
-        ),
-    )
-    # Rows are SQ-major (q, f); sort each SQ's resubmissions by time.
-    rt = resub_t.reshape(q, f)
-    order = segops.stable_argsort(rt, axis=1)
-    rows = jnp.arange(q, dtype=jnp.int32)[:, None]
+        def pick(x):
+            return x.reshape(q, f)[rows, order]
 
-    def pick(x):
-        return x.reshape(q, f)[rows, order]
+        rings = frontend.submit_grouped(
+            rings,
+            rt[rows, order],
+            pick(new_op),
+            pick(new_lba),
+            pick(jnp.ones((n,), jnp.int32)),
+            pick(batch.buf_id),
+            pick(new_req),
+            pick(resub_valid),
+            tenant=pick(tenant_rows),
+            fused=cfg.use_compaction,
+        )
 
-    rings = frontend.submit_grouped(
-        rings,
-        rt[rows, order],
-        pick(new_op),
-        pick(new_lba),
-        pick(jnp.ones((n,), jnp.int32)),
-        pick(batch.buf_id),
-        pick(new_req),
-        pick(resub_valid),
-        tenant=pick(tenant_rows),
-        fused=cfg.use_compaction,
-    )
-
-    # -- clock advance --------------------------------------------------------
-    # Discrete-event step with a poll quantum: each round ingests the
-    # submissions of a bounded virtual-time window (dispatchers poll
-    # continuously in the real emulator; the quantum is our emulation
-    # granularity — it bounds arrival-time rounding at <= quantum, far below
-    # the >=50us device latencies modeled). Idle gaps are skipped by jumping
-    # to the earliest pending submission.
-    dpos = rings.head % rings.depth
-    head_t = rings.submit_time[jnp.arange(q), dpos]
-    head_t = jnp.where(rings.tail > rings.head, head_t, FAR)
-    nxt = jnp.min(head_t)
-    stepped = state.clock + jnp.float32(cfg.poll_quantum_us)
-    clock = jnp.where(nxt < FAR, jnp.maximum(stepped, nxt), stepped)
+        # -- clock advance ----------------------------------------------------
+        # Discrete-event step with a poll quantum: each round ingests the
+        # submissions of a bounded virtual-time window (dispatchers poll
+        # continuously in the real emulator; the quantum is our emulation
+        # granularity — it bounds arrival-time rounding at <= quantum, far
+        # below the >=50us device latencies modeled). Idle gaps are skipped
+        # by jumping to the earliest pending submission.
+        dpos = rings.head % rings.depth
+        head_t = rings.submit_time[jnp.arange(q), dpos]
+        head_t = jnp.where(rings.tail > rings.head, head_t, FAR)
+        nxt = jnp.min(head_t)
+        stepped = state.clock + jnp.float32(cfg.poll_quantum_us)
+        clock = jnp.where(nxt < FAR, jnp.maximum(stepped, nxt), stepped)
 
     return EngineState(
         rings=rings, cq=cqr, device=dev, cache=cstate, clock=clock,
@@ -536,7 +551,8 @@ def _jit_runner(run_fn, donate: bool, sanitized: bool):
     ``checkify.checkify`` *inside* the jit boundary and the returned
     runner ``err.throw()``s on the host. The error pytree rides along as
     a regular output; the engine state itself is bit-exact with the
-    unsanitized run (the checks only observe).
+    unsanitized run (the checks only observe). Either runner has the
+    jit's ``lower``, so its compiled program can be read.
     """
     donate_argnums = (0,) if donate else ()
     if not sanitized:
@@ -551,6 +567,7 @@ def _jit_runner(run_fn, donate: bool, sanitized: bool):
         err.throw()
         return out
 
+    runner.lower = jitted.lower
     return runner
 
 
@@ -622,6 +639,7 @@ def make_sharded_array_runner(
     ``tests/test_fabric.py``).
 
     ``mesh`` defaults to all local devices on a ``(axis_name,)`` mesh.
+    The runner has the jit's ``lower``, as ``make_runner``'s does.
     """
     import numpy as np
     from jax.sharding import Mesh, PartitionSpec as P
@@ -651,6 +669,7 @@ def make_sharded_array_runner(
             )
         return sharded(states)
 
+    _run.lower = sharded.lower
     return _run
 
 

@@ -154,3 +154,30 @@ def test_exactness_runner_compiles_with_every_kernel(shape, monkeypatch):
     )
     assert compiled.as_text().count("tpu_custom_call") >= 4
     assert live < HBM_BYTES
+
+
+def test_benchmark_runner_keeps_its_stage_scopes(shape):
+    """The v5e compiler keeps the ``stage.*`` scopes of the benchmark
+    cell's runner: the write of the epoch's rows into the whole image is
+    ``stage.data_write``'s; the ``while`` of the scan over rounds belongs
+    to no stage."""
+    import re
+
+    from bench import harness, stages
+
+    cell = harness.load_cell("d40m.randread_qd256")
+    cfg, ssd, wl, plat = harness.program(cell)
+    compiled, _, _ = _runner_memory(shape, cfg, ssd, wl, plat,
+                                    cell.config["block_words"])
+    hlo = compiled.as_text()
+    scopes = stages.op_scopes(hlo)
+    image = f"f32[{ssd.num_blocks},{cell.config['block_words']}]"
+    writes = re.findall(r"^\s+%(fusion[.\d]*) = " + re.escape(image),
+                        hlo, re.M)
+    assert writes and {scopes.get(w) for w in writes} == {"data_write"}
+    scan = re.findall(r'^\s+%(while[.\d]*) = .* while\(.*'
+                      r'op_name="jit\(_run\)/while"', hlo, re.M)
+    assert len(scan) == 1 and scan[0] not in scopes
+    assert set(scopes.values()) == {
+        "fetch", "lock", "timing", "datapath", "flash", "cq", "account",
+        "data_read", "data_write", "resubmit"}
