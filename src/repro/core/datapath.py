@@ -15,12 +15,15 @@ dispatcher-side fetching (paper Fig. 9 interference).
 """
 from __future__ import annotations
 
-from typing import Tuple
+import dataclasses
+import math
+from typing import NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
 
 from repro.core.segops import (
+    block_counts,
     counting_sort_plan,
     queueing_scan,
     segment_rank,
@@ -38,32 +41,113 @@ from repro.core.types import (
 # Functional data movement.
 # ---------------------------------------------------------------------------
 
+class Window(NamedTuple):
+    """The epoch's valid rows as one dense list, moved ``width`` at a time.
+
+    A fetched batch is SQ-major with ``fetch_width`` rows per SQ, and the
+    valid rows of each SQ's block are a prefix (``frontend._gather_entries``).
+    So the k-th valid row lies in the last SQ whose first valid index is
+    at most k, found from the per-SQ counts alone: no sort, no scatter.
+    """
+
+    width: int                # W rows per chunk (static)
+    fetch_width: int          # F rows per SQ block (static)
+    starts: jax.Array         # (Q,) i32 index of each SQ's first valid row
+    total: jax.Array          # () i32 valid rows in the epoch
+    read_chunks: jax.Array    # () i32 trips of the read loop
+    write_chunks: jax.Array   # () i32 trips of the write loop
+
+    def chunk(self, batch: RequestBatch, c: jax.Array) -> RequestBatch:
+        """Rows ``[c*W, (c+1)*W)`` of the valid list, in batch order; the
+        padding past the last valid row is marked invalid."""
+        k = c * self.width + jnp.arange(self.width, dtype=jnp.int32)
+        before = self.starts[None, :] <= k[:, None]
+        q = jnp.sum(before, axis=1, dtype=jnp.int32) - 1
+        live = k < self.total
+        rows = jnp.where(live, q * self.fetch_width + k - self.starts[q], 0)
+        sub = jax.tree.map(lambda x: x[rows], batch)
+        return dataclasses.replace(sub, valid=live)
+
+
+def window_rows(cfg: EngineConfig, ssd: SSDConfig) -> int | None:
+    """Chunk width W of the windowed data path, or None where W would
+    cover the whole epoch (the full-width form is then used).
+
+    The drive retires about ``t_max_iops * poll_quantum_us`` requests a
+    round; W doubles that and rounds up to a power of two of at least 128.
+    """
+    n = cfg.num_sqs * cfg.fetch_width
+    expected = ssd.t_max_iops * cfg.poll_quantum_us * 1e-6
+    w = max(128, 1 << math.ceil(math.log2(max(2.0 * expected, 1.0))))
+    return w if w < n else None
+
+
+def data_window(batch: RequestBatch, fetch_width: int, width: int) -> Window:
+    """The ``Window`` over ``batch``: each loop runs ``ceil(valid / W)``
+    chunks, and none where the epoch holds no valid row of its kind."""
+    counts = block_counts(batch.valid, fetch_width)
+    ends = jnp.cumsum(counts)
+    total = ends[-1]
+    chunks = (total + width - 1) // width
+    live = batch.valid.reshape(-1, fetch_width)
+    op = batch.opcode.reshape(-1, fetch_width)
+    return Window(
+        width, fetch_width, ends - counts, total,
+        jnp.where(jnp.any(live & (op == 0)), chunks, 0),
+        jnp.where(jnp.any(live & (op == 1)), chunks, 0),
+    )
+
+
 def apply_reads(
     flash: jax.Array, bufs: jax.Array, batch: RequestBatch,
-    use_pallas: bool = False,
+    use_pallas: bool = False, window: Window | None = None,
 ) -> jax.Array:
-    """Copy flash[lba] into bufs[buf_id] for valid read requests."""
-    is_read = batch.valid & (batch.opcode == 0)
-    src = jnp.where(is_read, batch.lba, 0)
-    if use_pallas:
-        from repro.kernels import ops as kops
+    """Copy flash[lba] into bufs[buf_id] for valid read requests.
 
-        data = kops.block_gather(flash, src)
-    else:
-        data = flash[src]
-    dst = jnp.where(is_read, batch.buf_id, bufs.shape[0])
-    return bufs.at[dst].set(data, mode="drop")
+    With a ``window``, only the epoch's valid rows are gathered, in
+    ``window.read_chunks`` chunks of ``window.width`` rows."""
+    def copy(bufs, b):
+        is_read = b.valid & (b.opcode == 0)
+        src = jnp.where(is_read, b.lba, 0)
+        if use_pallas:
+            from repro.kernels import ops as kops
+
+            data = kops.block_gather(flash, src)
+        else:
+            data = flash[src]
+        dst = jnp.where(is_read, b.buf_id, bufs.shape[0])
+        return bufs.at[dst].set(data, mode="drop")
+
+    if window is None:
+        return copy(bufs, batch)
+    return jax.lax.fori_loop(
+        0, window.read_chunks,
+        lambda c, bufs: copy(bufs, window.chunk(batch, c)), bufs,
+    )
 
 
 def apply_writes(
-    flash: jax.Array, bufs: jax.Array, batch: RequestBatch
+    flash: jax.Array, bufs: jax.Array, batch: RequestBatch,
+    window: Window | None = None,
 ) -> jax.Array:
-    """Copy bufs[buf_id] into flash[lba] for valid write requests."""
-    is_write = batch.valid & (batch.opcode == 1)
-    src = jnp.where(is_write, batch.buf_id, 0)
-    data = bufs[src]
-    dst = jnp.where(is_write, batch.lba, flash.shape[0])
-    return flash.at[dst].set(data, mode="drop")
+    """Copy bufs[buf_id] into flash[lba] for valid write requests.
+
+    With a ``window``, only the epoch's valid rows are scattered, in
+    ``window.write_chunks`` chunks of ``window.width`` rows that keep
+    the batch's order; the loop carries the image in place."""
+    def copy(flash, b):
+        is_write = b.valid & (b.opcode == 1)
+        src = jnp.where(is_write, b.buf_id, 0)
+        data = bufs[src]
+        dst = jnp.where(is_write, b.lba, flash.shape[0])
+        return flash.at[dst].set(data, mode="drop")
+
+    if window is None:
+        return copy(flash, batch)
+    return jax.lax.fori_loop(
+        0, window.write_chunks,
+        lambda c, flash: copy(flash, window.chunk(batch, c)), flash,
+    )
 
 
 # ---------------------------------------------------------------------------

@@ -115,6 +115,10 @@ class Metrics:
     # lat_hist — the tail-latency view the ready-time lock study (fig29)
     # reads its per-class p99 and SLO-attainment numbers from.
     tenant_lat_hist: jax.Array   # (T, HIST_BUCKETS) f32
+    # Rows the functional data path gathered or scattered, reads plus
+    # writes: 2 x the epoch per round in the full-width form, chunks x W
+    # in the windowed one (``datapath.Window``).
+    data_rows: jax.Array         # f32 count
 
     @staticmethod
     def zero(num_tenants: int = 1) -> "Metrics":
@@ -129,7 +133,7 @@ class Metrics:
             jnp.zeros((HIST_BUCKETS,), jnp.float32), z,
             jnp.zeros((num_tenants,), jnp.float32),
             jnp.zeros((num_tenants,), jnp.float32),
-            jnp.zeros((num_tenants, HIST_BUCKETS), jnp.float32),
+            jnp.zeros((num_tenants, HIST_BUCKETS), jnp.float32), z,
         )
 
     def iops(self) -> jax.Array:
@@ -305,7 +309,12 @@ def engine_round(
     ssd: SSDConfig,
     wl: "Workload | WorkloadConfig",
     plat: PlatformModel,
+    data_window: int | None = None,
 ) -> EngineState:
+    """One round. ``data_window`` (static) is the chunk width W of the
+    windowed data path (``datapath.window_rows``); None moves the whole
+    epoch's rows, the form a vmapped runner needs (a per-drive trip count
+    would turn the loop into a select over the whole image)."""
     wl = as_workload(wl)
     pipe = DevicePipeline(cfg, ssd, plat)
     q, f = cfg.num_sqs, cfg.fetch_width
@@ -362,11 +371,23 @@ def engine_round(
 
     # -- functional data movement --------------------------------------------
     flash, bufs = state.flash, state.bufs
+    data_rows = jnp.float32(0)
     if cfg.emulate_data:
         with jax.named_scope("stage.data_read"):
-            bufs = datapath.apply_reads(flash, bufs, batch, cfg.use_pallas)
+            # The window is passed only where there is one, so the
+            # full-width form calls apply_* exactly as before.
+            kw, data_rows = {}, jnp.float32(2 * n)
+            if data_window is not None:
+                win = datapath.data_window(batch, f, data_window)
+                kw = {"window": win}
+                data_rows = (
+                    (win.read_chunks + win.write_chunks) * data_window
+                ).astype(jnp.float32)
+            bufs = datapath.apply_reads(
+                flash, bufs, batch, cfg.use_pallas, **kw
+            )
         with jax.named_scope("stage.data_write"):
-            flash = datapath.apply_writes(flash, bufs, batch)
+            flash = datapath.apply_writes(flash, bufs, batch, **kw)
 
     # -- workload-driven resubmission (stage-0 cache filters first) ----------
     # Rows are SQ-major (q, f); a row's tenant is its SQ's static class.
@@ -461,6 +482,7 @@ def engine_round(
             tenant_completed=m.tenant_completed + tenant_completed,
             tenant_sum_e2e=m.tenant_sum_e2e + tenant_sum_e2e,
             tenant_lat_hist=m.tenant_lat_hist + tenant_lat_hist,
+            data_rows=m.data_rows + data_rows,
         )
 
     with jax.named_scope("stage.resubmit"):
@@ -521,12 +543,14 @@ def run(
     wl: "Workload | WorkloadConfig",
     plat: PlatformModel,
     rounds: int,
+    data_window: int | None = None,
 ) -> EngineState:
-    """Run ``rounds`` engine rounds under jit (lax.scan over rounds)."""
+    """Run ``rounds`` engine rounds under jit (lax.scan over rounds);
+    ``data_window`` as in ``engine_round``."""
     wl = as_workload(wl)
 
     def body(s, _):
-        return engine_round(s, cfg, ssd, wl, plat), None
+        return engine_round(s, cfg, ssd, wl, plat, data_window), None
 
     out, _ = jax.lax.scan(body, state, None, length=rounds)
     return out
@@ -590,14 +614,18 @@ def make_runner(
     violated invariant. Virtual time is unchanged — the sanitized
     runner's output state is bit-exact with the default runner's
     (pinned by tests/test_sanitize.py).
+
+    The data path moves only the epoch's valid rows, in chunks of
+    ``datapath.window_rows(cfg, ssd)``; the array runners move them all.
     """
     wl = as_workload(wl)
     sanitized = sanitize or cfg.sanitize
     if sanitized:
         cfg = cfg.replace(sanitize=True)
+    window = datapath.window_rows(cfg, ssd)
 
     def _run(state: EngineState) -> EngineState:
-        return run(state, cfg, ssd, wl, plat, rounds)
+        return run(state, cfg, ssd, wl, plat, rounds, window)
 
     return _jit_runner(_run, donate, sanitized)
 

@@ -156,22 +156,30 @@ def test_exactness_runner_compiles_with_every_kernel(shape, monkeypatch):
     assert live < HBM_BYTES
 
 
-def test_benchmark_runner_keeps_its_stage_scopes(shape):
+@pytest.fixture(scope="module")
+def bench_runner(shape):
+    """The benchmark cell's runner (``d40m.randread_qd256``), compiled."""
+    from bench import harness
+
+    cell = harness.load_cell("d40m.randread_qd256")
+    cfg, ssd, wl, plat = harness.program(cell)
+    words = cell.config["block_words"]
+    compiled, mem, _ = _runner_memory(shape, cfg, ssd, wl, plat, words)
+    return compiled, mem, f"f32[{ssd.num_blocks},{words}]"
+
+
+def test_benchmark_runner_keeps_its_stage_scopes(bench_runner):
     """The v5e compiler keeps the ``stage.*`` scopes of the benchmark
     cell's runner: the write of the epoch's rows into the whole image is
     ``stage.data_write``'s; the ``while`` of the scan over rounds belongs
     to no stage."""
     import re
 
-    from bench import harness, stages
+    from bench import stages
 
-    cell = harness.load_cell("d40m.randread_qd256")
-    cfg, ssd, wl, plat = harness.program(cell)
-    compiled, _, _ = _runner_memory(shape, cfg, ssd, wl, plat,
-                                    cell.config["block_words"])
+    compiled, _, image = bench_runner
     hlo = compiled.as_text()
     scopes = stages.op_scopes(hlo)
-    image = f"f32[{ssd.num_blocks},{cell.config['block_words']}]"
     writes = re.findall(r"^\s+%(fusion[.\d]*) = " + re.escape(image),
                         hlo, re.M)
     assert writes and {scopes.get(w) for w in writes} == {"data_write"}
@@ -181,3 +189,17 @@ def test_benchmark_runner_keeps_its_stage_scopes(shape):
     assert set(scopes.values()) == {
         "fetch", "lock", "timing", "datapath", "flash", "cq", "account",
         "data_read", "data_write", "resubmit"}
+
+
+def test_benchmark_runner_holds_one_image(bench_runner):
+    """The windowed data path's loops carry the 8 GiB image in place: no
+    second copy of it in temporary memory. Inside the scan the round holds
+    one read loop, one write loop and the lock stage's loop."""
+    import re
+
+    compiled, mem, _ = bench_runner
+    assert mem.temp_size_in_bytes < 2**30
+    whiles = re.findall(r'^\s+%while[.\d]* = .* while\(.*op_name="([^"]*)"',
+                        compiled.as_text(), re.M)
+    loops = sorted(n.split("/")[-2] for n in whiles if "stage." in n)
+    assert loops == ["stage.data_read", "stage.data_write", "stage.lock"]
