@@ -430,9 +430,11 @@ def phase_sharded(cfg: EngineConfig, ssd: SSDConfig, wl, *, drives: int,
     sharded = engine.make_sharded_array_runner(cfg, ssd, wl, plat, rounds,
                                                mesh=mesh)
     t0 = time.perf_counter()
-    out_s = jax.block_until_ready(
-        sharded(jax.device_put(states, NamedSharding(mesh, P("dev"))))
-    )
+    # The sharded runner consumes its input: give it a copy, since the
+    # one-device runs below take their drives from ``states``.
+    out_s = jax.block_until_ready(sharded(jax.device_put(
+        engine.unalias(states), NamedSharding(mesh, P("dev"))
+    )))
     sharded_s = time.perf_counter() - t0
     spans = {len(x.sharding.device_set) for x in jax.tree.leaves(out_s)}
     check(spans == {len(devices)},

@@ -666,6 +666,12 @@ def make_sharded_array_runner(
     to ``make_array_runner`` (asserted bit-exactly in
     ``tests/test_fabric.py``).
 
+    The call consumes its input: the stacked state is donated
+    (``donate_argnums``), so each chip holds one copy of its drives'
+    images, and the steady-state loop ``states = call(states)`` runs in
+    place. A caller that needs its input afterwards passes a copy
+    (``unalias(states)``).
+
     ``mesh`` defaults to all local devices on a ``(axis_name,)`` mesh.
     The runner has the jit's ``lower``, as ``make_runner``'s does.
     """
@@ -684,7 +690,7 @@ def make_sharded_array_runner(
     sharded = jax.jit(jax.shard_map(
         _shard, mesh=mesh, in_specs=P(axis_name), out_specs=P(axis_name),
         check_vma=False,
-    ))
+    ), donate_argnums=(0,))
     mesh_size = int(np.prod(mesh.devices.shape))
 
     def _run(states: EngineState) -> EngineState:

@@ -203,3 +203,38 @@ def test_benchmark_runner_holds_one_image(bench_runner):
                         compiled.as_text(), re.M)
     loops = sorted(n.split("/")[-2] for n in whiles if "stage." in n)
     assert loops == ["stage.data_read", "stage.data_write", "stage.lock"]
+
+
+def test_array_cell_runner_donates_and_fits_each_chip(topo):
+    """The ``array8.randread_qd256`` cell's sharded runner compiles for a
+    2x2 v5e mesh, two drives a chip. It consumes its stacked state in
+    place, so each chip holds its two 4 GiB images once and fits its
+    16 GB; without the donation a second copy would not fit."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from bench import harness
+
+    cell = harness.load_cell("array8.randread_qd256")
+    cfg, ssd, wl, plat = harness.program(cell)
+    drives, words = cell.config["drives"], cell.config["block_words"]
+    mesh = Mesh(np.asarray(topo.devices), ("dev",))
+    sharding = NamedSharding(mesh, P("dev"))
+    state = jax.eval_shape(
+        lambda: engine.init_array_state(cfg, ssd, wl, drives, words)
+    )
+    state = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sharding),
+        state,
+    )
+    runner = engine.make_sharded_array_runner(
+        cfg, ssd, wl, plat, cell.traffic["rounds_per_call"], mesh=mesh
+    )
+    mem = runner.lower(state).compile().memory_analysis()
+    images = drives // len(topo.devices) * ssd.num_blocks * words * 4
+    live = (
+        mem.argument_size_in_bytes + mem.output_size_in_bytes
+        - mem.alias_size_in_bytes + mem.temp_size_in_bytes
+    )
+    assert mem.alias_size_in_bytes >= images
+    assert live < HBM_BYTES < live + images
